@@ -63,6 +63,7 @@ def main() -> None:
         ("spec", spec_bench.run),
     ]
     meta = _snapshot_meta() if args.json else None
+    failed = []
     print("name,us_per_call,derived")
     for name, fn in suites:
         if only and name not in only:
@@ -80,8 +81,9 @@ def main() -> None:
                 print(f"{n},{us:.2f},{derived}")
                 rows.append({"name": n, "us_per_call": round(us, 2),
                              "derived": str(derived)})
-        except Exception as e:  # noqa: BLE001
+        except Exception as e:  # noqa: BLE001 - report, run the rest, fail
             print(f"{name}/ERROR,0,{e!r}", file=sys.stdout)
+            failed.append(name)
             rows = None
         if args.json and rows is not None:
             import jax
@@ -94,6 +96,8 @@ def main() -> None:
                 f.write("\n")
             print(f"# wrote {os.path.normpath(path)}", file=sys.stderr)
     sys.stdout.flush()
+    if failed:
+        sys.exit(f"suites raised: {','.join(failed)}")
 
 
 if __name__ == '__main__':

@@ -187,89 +187,60 @@ def _burst_rows(api, params, cfg, *, max_batch, seed, quick=True):
     return rows
 
 
-_MESH_SCRIPT = """
-import json, sys, time
-import jax
-import numpy as np
-from benchmarks import serve_bench
-from repro.configs import smoke_config
-from repro.models import get_model
-from repro.launch.mesh import make_mesh
-from repro.serving.scheduler import poisson_workload
-
-requests, max_batch, rate, seed, n = json.loads(sys.argv[1])
-# f32 compute: random-init bf16 argmax gaps (~1e-3) sit below sharded-
-# matmul reduction-reorder noise, and the point of the identity assert is
-# the engine, not tie-breaking luck (tests/test_engine_parity.py holds the
-# trained-model token bar)
-cfg = smoke_config("stablelm-3b").replace(compute_dtype="float32")
-api = get_model(cfg)
-params = api.init(jax.random.PRNGKey(0))
-workload = poisson_workload(requests, rate=rate,
-                            prompt_lens=(5, 8, 12, 16), max_new=(4, 16),
-                            vocab=cfg.vocab, seed=seed)
-warmup = poisson_workload(max(4, max_batch), rate=rate,
-                          prompt_lens=(5, 8, 12, 16), max_new=(4, 16),
-                          vocab=cfg.vocab, seed=seed + 10 ** 6)
-rows = []
-ref = None
-for name, mesh in (("1dev", None),
-                   (f"mesh{n}", make_mesh((n,), ("model",)))):
-    from repro.serving import ServeEngine, Telemetry
-    tm = Telemetry()
-    eng = ServeEngine(api, params, max_batch=max_batch, max_len=64,
-                      mesh=mesh, telemetry=tm)
-    # compile every prefill bucket + the decode step outside the timed
-    # drive: GSPMD partitioning makes the mesh engine's compiles much
-    # slower, and compile time is not what this row prices
-    serve_bench._drive(eng, warmup)
-    tm.reset()           # drop warmup latencies; measured drive only
-    res, toks, dt = serve_bench._drive(eng, workload)
-    if ref is None:
-        ref = res
-    else:
-        assert list(res.values()) == list(ref.values()), \\
-            "mesh outputs diverged from single-device"
-    rows.append((f"serve/{name}_tok_s", dt / toks * 1e6,
-                 f"{toks / dt:.1f} tok/s"))
-    rows += serve_bench._pct_rows(f"serve/{name}", tm)
-    rows.append((f"serve/{name}_kv_bytes_per_dev", 0.0,
-                 f"{eng.stats['kv_bytes_per_device'] / 1024:.1f} KiB"))
-print("RESULT:" + json.dumps(rows))
-"""
-
-
 def _mesh_rows(quick: bool = True, *, requests, max_batch, rate, seed,
                mesh: int = 2):
-    """Tensor-parallel slot engine vs single-device, same workload, in a
-    subprocess that forces ``mesh`` virtual host devices (the parent
-    process already initialized jax with one). Token identity is asserted
-    inside the subprocess; wall-clock is recorded honestly — on a
-    single-core CPU host the mesh row prices the collectives (virtual
+    """Tensor-parallel slot engine vs single-device, same workload, in
+    this process over the first ``mesh`` visible devices (on CPU launch
+    with XLA_FLAGS=--xla_force_host_platform_device_count=N). Raises when
+    fewer are visible. Token identity is asserted; wall-clock is recorded
+    honestly — on a CPU host the mesh row prices the collectives (virtual
     devices serialize), while on real multi-chip hosts the identical code
     path is where the speedup comes from."""
-    import json
-    import subprocess
+    from repro.launch.mesh import make_mesh
 
-    env = dict(os.environ)
-    env.pop("XLA_FLAGS", None)
-    root = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..")
-    env["PYTHONPATH"] = os.pathsep.join(
-        [os.path.join(root, "src"), root])
-    prelude = ("import os\n"
-               "os.environ['XLA_FLAGS'] = "
-               f"'--xla_force_host_platform_device_count={mesh}'\n")
-    arg = json.dumps([requests, max_batch, rate, seed, mesh])
-    try:
-        proc = subprocess.run(
-            [sys.executable, "-c", prelude + _MESH_SCRIPT, arg], env=env,
-            capture_output=True, text=True, timeout=1800, check=True)
-        line = [ln for ln in proc.stdout.splitlines()
-                if ln.startswith("RESULT:")][-1]
-        return [tuple(r) for r in json.loads(line[len("RESULT:"):])]
-    except (subprocess.SubprocessError, IndexError) as e:  # noqa: BLE001
-        err = getattr(e, "stderr", "") or str(e)
-        return [("serve/mesh_ERROR", 0.0, repr(err[-200:]))]
+    devices = jax.devices()
+    if len(devices) < mesh:
+        raise RuntimeError(
+            f"serve/mesh{mesh} needs {mesh} devices, {len(devices)} "
+            "visible (on CPU set XLA_FLAGS=--xla_force_host_platform_"
+            f"device_count={mesh} before launch)")
+    # f32 compute: random-init bf16 argmax gaps (~1e-3) sit below sharded-
+    # matmul reduction-reorder noise, and the point of the identity assert
+    # is the engine, not tie-breaking luck (tests/test_engine_parity.py
+    # holds the trained-model token bar)
+    cfg = smoke_config("stablelm-3b").replace(compute_dtype="float32")
+    api = get_model(cfg)
+    params = api.init(jax.random.PRNGKey(0))
+    workload = poisson_workload(requests, rate=rate,
+                                prompt_lens=(5, 8, 12, 16), max_new=(4, 16),
+                                vocab=cfg.vocab, seed=seed)
+    warmup = poisson_workload(max(4, max_batch), rate=rate,
+                              prompt_lens=(5, 8, 12, 16), max_new=(4, 16),
+                              vocab=cfg.vocab, seed=seed + 10 ** 6)
+    rows, ref = [], None
+    for name, m in (("1dev", None),
+                    (f"mesh{mesh}", make_mesh((mesh,), ("model",),
+                                              devices=devices[:mesh]))):
+        tm = Telemetry()
+        eng = ServeEngine(api, params, max_batch=max_batch, max_len=64,
+                          mesh=m, telemetry=tm)
+        # compile every prefill bucket + the decode step outside the timed
+        # drive: GSPMD partitioning makes the mesh engine's compiles much
+        # slower, and compile time is not what this row prices
+        _drive(eng, warmup)
+        tm.reset()           # drop warmup latencies; measured drive only
+        res, toks, dt = _drive(eng, workload)
+        if ref is None:
+            ref = res
+        else:
+            assert list(res.values()) == list(ref.values()), \
+                "mesh outputs diverged from single-device"
+        rows.append((f"serve/{name}_tok_s", dt / toks * 1e6,
+                     f"{toks / dt:.1f} tok/s"))
+        rows += _pct_rows(f"serve/{name}", tm)
+        rows.append((f"serve/{name}_kv_bytes_per_dev", 0.0,
+                     f"{eng.stats['kv_bytes_per_device'] / 1024:.1f} KiB"))
+    return rows
 
 
 def _trained_smoke_lm(steps: int = 200):

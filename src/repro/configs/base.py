@@ -102,8 +102,8 @@ class ModelConfig:
     kv_cache: str = "auto"
     # Packed-weight lowering for the binarized self-draft of speculative
     # decoding: auto | xla_xnor | int8_mxu | pallas_xnor (kernels/ops.py
-    # SPEC_DRAFT_IMPLS). auto keeps resolve_impl's backend default (XLA
-    # XNOR twin on CPU, Pallas popcount kernel on TPU); int8_mxu lowers
+    # SPEC_DRAFT_IMPLS). auto keeps resolve_impl's default (the XLA XNOR
+    # twin; pallas_xnor compiles only in interpret mode); int8_mxu lowers
     # sign bits to +-1 int8 dot_general — the MXU path. All lowerings are
     # exact-int32 twins, so the knob is pure wall-clock, never tokens.
     spec_draft_impl: str = "auto"

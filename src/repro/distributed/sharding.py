@@ -160,6 +160,13 @@ def active_mesh():
     return _ACTIVE_MESH
 
 
+def sharded_mesh_active() -> bool:
+    """True while a mesh of more than one device is active. GSPMD cannot
+    partition a Mosaic (Pallas TPU) kernel, so "auto" lowerings resolve
+    to their XLA twins then (read at trace time, like active_mesh)."""
+    return _ACTIVE_MESH is not None and getattr(_ACTIVE_MESH, "size", 1) > 1
+
+
 def with_logical_constraint(x, axes):
     """Constrain activation sharding by logical axis names (no-op when no
     rules are active, e.g. in single-device tests)."""

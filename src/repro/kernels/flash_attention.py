@@ -51,10 +51,11 @@ def _round_up(x: int, m: int) -> int:
 # Pallas kernel
 # ---------------------------------------------------------------------------
 
-def _flash_kernel(q_ref, k_ref, v_ref, kvlen_ref, o_ref,
+def _flash_kernel(kvlen_ref, q_ref, k_ref, v_ref, o_ref,
                   m_scr, l_scr, acc_scr, *,
                   scale: float, causal: bool, q_offset: int,
                   bq: int, bk: int, nk: int):
+    kv_len = kvlen_ref[pl.program_id(0)]
     i = pl.program_id(2)
     j = pl.program_id(3)
 
@@ -81,7 +82,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, kvlen_ref, o_ref,
             preferred_element_type=jnp.float32) * scale   # (bq, bk)
 
         cols = col0 + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-        valid = cols < kvlen_ref[0, 0]
+        valid = cols < kv_len
         if causal:
             rows = row0 + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
             valid = valid & (rows >= cols)
@@ -109,7 +110,11 @@ def _flash_kernel(q_ref, k_ref, v_ref, kvlen_ref, o_ref,
     "causal", "scale", "q_offset", "bq", "bk", "interpret"))
 def _flash_call(qt, kt, vt, kvlen, *, causal, scale, q_offset, bq, bk,
                 interpret):
-    """qt (B, Hq, Sp, D), kt/vt (B, Hkv, Tp, D/Dv), kvlen (B, 1) int32."""
+    """qt (B, Hq, Sp, D), kt/vt (B, Hkv, Tp, D/Dv), kvlen (B,) int32.
+
+    kvlen is a scalar-prefetch operand: it lands in SMEM before the grid
+    runs, so any batch size tiles (a (1, 1) VMEM block of a (B, 1) array
+    does not satisfy the chip's (8, 128) block rule once B > 1)."""
     b, hq, sp, d = qt.shape
     hkv, tp, dv = kt.shape[1], kt.shape[2], vt.shape[3]
     g = hq // hkv
@@ -118,30 +123,32 @@ def _flash_call(qt, kt, vt, kvlen, *, causal, scale, q_offset, bq, bk,
     return pl.pallas_call(
         functools.partial(_flash_kernel, scale=scale, causal=causal,
                           q_offset=q_offset, bq=bq, bk=bk, nk=nk),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1, bq, d), lambda b, h, i, j: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, bk, d),
-                         lambda b, h, i, j: (b, h // g, j, 0)),
-            pl.BlockSpec((1, 1, bk, dv),
-                         lambda b, h, i, j: (b, h // g, j, 0)),
-            pl.BlockSpec((1, 1), lambda b, h, i, j: (b, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, bq, dv),
-                               lambda b, h, i, j: (b, h, i, 0)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=grid,
+            in_specs=[
+                pl.BlockSpec((1, 1, bq, d),
+                             lambda b, h, i, j, _: (b, h, i, 0)),
+                pl.BlockSpec((1, 1, bk, d),
+                             lambda b, h, i, j, _: (b, h // g, j, 0)),
+                pl.BlockSpec((1, 1, bk, dv),
+                             lambda b, h, i, j, _: (b, h // g, j, 0)),
+            ],
+            out_specs=pl.BlockSpec((1, 1, bq, dv),
+                                   lambda b, h, i, j, _: (b, h, i, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((bq, 128), jnp.float32),   # running max
+                pltpu.VMEM((bq, 128), jnp.float32),   # running denominator
+                pltpu.VMEM((bq, dv), jnp.float32),    # unnormalized output
+            ]),
         out_shape=jax.ShapeDtypeStruct((b, hq, sp, dv), vt.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((bq, 128), jnp.float32),   # running max
-            pltpu.VMEM((bq, 128), jnp.float32),   # running denominator
-            pltpu.VMEM((bq, dv), jnp.float32),    # unnormalized output
-        ],
         interpret=interpret,
-    )(qt, kt, vt, kvlen)
+    )(kvlen, qt, kt, vt)
 
 
 def _flash_fwd_impl(q, k, v, kvlen, *, causal, scale, q_offset, bq, bk,
                     interpret):
-    """Padding + layout around _flash_call. kvlen is always a (B, 1) int32
+    """Padding + layout around _flash_call. kvlen is always a (B,) int32
     array here (the public wrapper normalizes None/scalars)."""
     b, s, hq, d = q.shape
     t = k.shape[1]
@@ -181,7 +188,7 @@ def _make_flash_vjp(causal, scale, q_offset, bq, bk, interpret):
         q, k, v, kvlen = res
         _, pull = jax.vjp(
             lambda q, k, v: blockwise_attention_xla(
-                q, k, v, causal=causal, kv_len=kvlen[:, 0], scale=scale,
+                q, k, v, causal=causal, kv_len=kvlen, scale=scale,
                 q_offset=q_offset, q_block=max(bq, 8), kv_block=max(bk, 8)),
             q, k, v)
         dq, dk, dv = pull(g.astype(v.dtype))
@@ -209,11 +216,11 @@ def flash_attention_pallas(q, k, v, *, causal: bool, kv_len=None,
 
     # padded keys are masked the same way short caches are: via kv_len
     if kv_len is None:
-        kvlen = jnp.full((b, 1), t, jnp.int32)
+        kvlen = jnp.full((b,), t, jnp.int32)
     else:
         kvlen = jnp.minimum(
             jnp.broadcast_to(jnp.asarray(kv_len, jnp.int32).reshape(-1),
-                             (b,)), t).reshape(b, 1)
+                             (b,)), t)
 
     return _make_flash_vjp(causal, scale, q_offset, bq, bk, interpret)(
         q, k, v, kvlen)

@@ -21,7 +21,12 @@ XLA lowerings agree bit for bit (same op order, same rounding).
 
 All entrypoints take (..., D) and quantize along the last axis; rows are
 flattened to a (N, D) grid for the Pallas calls. ``impl="auto"`` resolves
-like the attention backends: XLA twin on CPU, Pallas on accelerators.
+like the attention backends for int8 (XLA twin on CPU and under a
+multi-device mesh, Pallas on one accelerator) and to the XLA twin
+everywhere for binary: packing sums
+unsigned lanes and splits the lane axis into (D/32, 32), and unpacking
+casts uint32 to float, none of which the TPU compiler lowers, so the
+binary Pallas kernels stay interpret-mode references.
 """
 
 from __future__ import annotations
@@ -37,13 +42,17 @@ from repro.core.binarize import LANE_BITS, packed_len
 KV_QUANT_IMPLS = ("auto", "xla", "pallas")
 
 
-def resolve_kv_quant_impl(impl: str = "auto") -> str:
+def resolve_kv_quant_impl(impl: str = "auto", *, codec: str = "int8") -> str:
     if impl not in KV_QUANT_IMPLS:
         raise ValueError(
             f"unknown kv-quant impl {impl!r}; known: {KV_QUANT_IMPLS}")
     if impl != "auto":
         return impl
-    return "xla" if jax.default_backend() == "cpu" else "pallas"
+    from repro.distributed.sharding import sharded_mesh_active
+    if codec == "binary" or sharded_mesh_active() \
+            or jax.default_backend() == "cpu":
+        return "xla"
+    return "pallas"
 
 
 # ---------------------------------------------------------------------------
@@ -284,14 +293,14 @@ def kv_dequant_int8(values, scales, *, dtype=jnp.bfloat16,
 
 
 def kv_quant_binary(x, *, impl: str = "auto"):
-    impl = resolve_kv_quant_impl(impl)
+    impl = resolve_kv_quant_impl(impl, codec="binary")
     return (kv_quant_binary_pallas(x) if impl == "pallas"
             else kv_quant_binary_xla(x))
 
 
 def kv_dequant_binary(packed, scales, d: int, *, dtype=jnp.bfloat16,
                       impl: str = "auto"):
-    impl = resolve_kv_quant_impl(impl)
+    impl = resolve_kv_quant_impl(impl, codec="binary")
     if impl == "pallas":
         return kv_dequant_binary_pallas(packed, scales, d, dtype=dtype)
     return kv_dequant_binary_xla(packed, scales, d, dtype)

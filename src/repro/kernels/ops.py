@@ -29,22 +29,32 @@ from repro.kernels.int8_matmul import int8_matmul_pallas
 
 # packed-weight lowering choices for the binary self-draft (threaded from
 # ModelConfig.spec_draft_impl down to nn/layers.dense_apply): "auto" keeps
-# the resolve_impl default (XLA XNOR twin on CPU, Pallas popcount kernel
-# elsewhere); "int8_mxu" is the +-1 int8 dot_general MXU twin.
+# the resolve_impl default (the XLA XNOR twin on every backend); "int8_mxu"
+# is the +-1 int8 dot_general MXU twin; "pallas_xnor" runs only in
+# interpret mode (Mosaic refuses its lane-serial popcount loop).
 SPEC_DRAFT_IMPLS = ("auto", "xla_xnor", "int8_mxu", "pallas_xnor")
 
 
 def resolve_impl(mode: str, impl: str = "auto") -> str:
-    """mode in {xnor, int8, bf16} -> concrete impl for this backend."""
+    """mode in {xnor, int8, bf16} -> concrete impl for this backend.
+
+    xnor resolves to the XLA twin everywhere: the Pallas popcount kernel
+    indexes packed lanes one at a time in (bm, 8)-lane blocks, which the
+    TPU compiler refuses, so it stays an interpret-mode reference. int8
+    takes the Pallas kernel on one accelerator; on CPU, and under a
+    multi-device mesh (GSPMD cannot partition a Mosaic kernel), the XLA
+    twin."""
     if impl != "auto":
         return impl
     if mode == "bf16":
         return "bf16"
-    on_cpu = jax.default_backend() == "cpu"
     if mode == "xnor":
-        return "xla_xnor" if on_cpu else "pallas_xnor"
+        return "xla_xnor"
     if mode == "int8":
-        return "xla_int8" if on_cpu else "pallas_int8"
+        from repro.distributed.sharding import sharded_mesh_active
+        if jax.default_backend() == "cpu" or sharded_mesh_active():
+            return "xla_int8"
+        return "pallas_int8"
     raise ValueError(f"unknown binary mode {mode!r}")
 
 

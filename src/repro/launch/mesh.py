@@ -6,42 +6,28 @@ from __future__ import annotations
 import jax
 
 
-def _make(shape, axes):
-    # AxisType landed with jax's explicit-sharding API (0.5+); Auto is the
-    # pre-0.5 default, so on older jax omitting the kwarg is equivalent.
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            shape, axes,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
-
-
 def make_production_mesh(*, multi_pod: bool = False):
     """v5e: 16x16 = 256 chips/pod; multi_pod adds a leading 2-pod axis."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return _make(shape, axes)
+    return make_mesh(shape, axes)
 
 
-def make_mesh(shape, axes):
-    """Arbitrary mesh for tests/elastic restarts."""
-    return _make(tuple(shape), tuple(axes))
+def make_mesh(shape, axes, *, devices=None):
+    """Arbitrary mesh for tests/elastic restarts; ``devices`` (default: all
+    visible) lets a mesh span the first few devices of a larger host."""
+    return jax.make_mesh(
+        tuple(shape), tuple(axes),
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+        devices=devices)
 
 
 def set_mesh(mesh):
-    """Context manager activating `mesh`: jax.set_mesh on 0.5+; on older
-    jax the Mesh object itself is the (equivalent) context manager."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
+    """Context manager activating ``mesh``."""
+    return jax.set_mesh(mesh)
 
 
 def shard_map(fn, *, mesh, in_specs, out_specs):
-    """jax.shard_map with replication checking off, on any jax version
-    (pre-0.6 spells it jax.experimental.shard_map / check_rep)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(fn, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=False)
+    """jax.shard_map with replication checking off."""
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)

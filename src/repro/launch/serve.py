@@ -18,12 +18,16 @@ Chrome trace-event JSON — open https://ui.perfetto.dev and drag the file
 in to see queued -> prefill -> first-token -> decode/spec-wave per
 request next to the engine's per-tick phase lane. ``--stats-every N``
 logs a one-line summary every N ticks; ``--xla-profile DIR`` addition-
-ally records a jax.profiler device trace (degrades to a one-time warning
-on backends without profiler support):
+ally records a jax.profiler device trace (a profiler that fails to start
+is an error):
 
   PYTHONPATH=src python -m repro.launch.serve --arch stablelm-3b --smoke \
       --requests 8 --trace-out /tmp/serve_trace.json \
       --metrics-out /tmp/serve_metrics.json --stats-every 8
+
+Run as a module, the launcher keeps XLA's persistent compilation cache in
+``$JAX_COMPILATION_CACHE_DIR`` or, unset, in ``.jax_cache/`` at the root
+of the checkout (launch/compile_cache.py).
 """
 
 from __future__ import annotations
@@ -84,7 +88,24 @@ def _serve_http(args, eng, telemetry):
     return dict(eng.results)
 
 
-def main(argv=None):
+def init_params(api, mesh):
+    """Random init as one jitted program. At full width eager init
+    dispatches every op of every layer separately; under a mesh the
+    params land sharded, never whole on the first device."""
+    if mesh is None:
+        return jax.jit(api.init)(jax.random.PRNGKey(0))
+    from repro.launch import specs
+    _, p_sh = specs.param_shardings(api, mesh,
+                                    specs.mesh_rules_for(api.cfg, mesh))
+    return jax.jit(api.init, out_shardings=p_sh)(jax.random.PRNGKey(0))
+
+
+def build(argv=None):
+    """Parse the command line and construct the engine.
+
+    Returns ``(args, cfg, eng, telemetry)``; ``drive`` serves with them.
+    Split from ``main`` so a caller can inspect the engine it built (the
+    chip smoke test reads per-device residency before serving)."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCHS, default="stablelm-3b")
     ap.add_argument("--smoke", action="store_true")
@@ -121,9 +142,10 @@ def main(argv=None):
                     choices=["auto", "xla_xnor", "int8_mxu", "pallas_xnor"],
                     help="packed-matmul lowering for the binary draft "
                          "(kernels/ops.py SPEC_DRAFT_IMPLS; auto = XLA "
-                         "XNOR twin on CPU, Pallas popcount on TPU; "
-                         "int8_mxu = +-1 int8 dot_general). All lowerings "
-                         "are exact-int32 twins: tokens never change")
+                         "XNOR twin; pallas_xnor runs in interpret mode "
+                         "only; int8_mxu = +-1 int8 dot_general). All "
+                         "lowerings are exact-int32 twins: tokens never "
+                         "change")
     ap.add_argument("--draft", default="binary",
                     choices=["binary", "none"],
                     help="speculative draft model: 'binary' = the served "
@@ -155,9 +177,8 @@ def main(argv=None):
                          "slot engine only)")
     ap.add_argument("--xla-profile", default="", metavar="DIR",
                     help="also record a jax.profiler device trace into "
-                         "DIR (TensorBoard/Perfetto readable); warns "
-                         "once and keeps serving if the backend has no "
-                         "profiler support")
+                         "DIR (TensorBoard/Perfetto readable); a "
+                         "profiler that fails to start is an error")
     ap.add_argument("--stats-every", type=int, default=0, metavar="N",
                     help="log a one-line telemetry summary every N "
                          "engine ticks (0 = off; slot engine only)")
@@ -191,7 +212,17 @@ def main(argv=None):
         ap.error(f"--arch {args.arch}: {cfg.family} needs audio/image "
                  "inputs; this text-only launcher cannot serve it")
     api = get_model(cfg)
-    params = api.init(jax.random.PRNGKey(0))
+    mesh = None
+    if args.mesh:
+        from repro.launch.mesh import make_mesh
+        if jax.device_count() < args.mesh:
+            ap.error(f"--mesh {args.mesh} needs {args.mesh} devices but "
+                     f"only {jax.device_count()} are visible (on CPU set "
+                     "XLA_FLAGS=--xla_force_host_platform_device_count="
+                     f"{args.mesh})")
+        mesh = make_mesh((args.mesh,), ("model",),
+                         devices=jax.devices()[:args.mesh])
+    params = init_params(api, mesh)
     if args.ckpt_dir:
         last = C.latest_step(args.ckpt_dir)
         if last is not None:
@@ -208,15 +239,6 @@ def main(argv=None):
         cls = BucketEngine
     stop = frozenset(int(x) for x in args.stop_tokens.split(",") if x)
     spec_k = args.spec_decode if args.draft != "none" else 0
-    mesh = None
-    if args.mesh:
-        from repro.launch.mesh import make_mesh
-        if jax.device_count() < args.mesh:
-            ap.error(f"--mesh {args.mesh} needs {args.mesh} devices but "
-                     f"only {jax.device_count()} are visible (on CPU set "
-                     "XLA_FLAGS=--xla_force_host_platform_device_count="
-                     f"{args.mesh})")
-        mesh = make_mesh((args.mesh,), ("model",))
     telemetry = None
     if args.metrics_out or args.trace_out or args.stats_every \
             or args.xla_profile or args.http:
@@ -257,8 +279,16 @@ def main(argv=None):
                   temperature=args.temperature, seed=args.seed,
                   attn_impl=args.attn_impl, kv_cache=args.kv_cache,
                   mesh=mesh)
+    return args, cfg, eng, telemetry
+
+
+def drive(args, cfg, eng, telemetry):
+    """Serve over --http, or run the synthetic --requests batch to
+    completion; returns {request id: generated tokens}."""
     if args.http:
         return _serve_http(args, eng, telemetry)
+    plens = [int(x) for x in args.prompt_lens.split(",")]
+    stop = frozenset(int(x) for x in args.stop_tokens.split(",") if x)
     rng = np.random.default_rng(0)
     for i in range(args.requests):
         plen = int(rng.choice(plens))
@@ -267,10 +297,9 @@ def main(argv=None):
             eng.add_request(prompt, max_new=args.max_new, stop_tokens=stop)
         else:
             eng.add_request(prompt, max_new=args.max_new)
-    profiling = False
     if args.xla_profile:
         from repro.serving.telemetry import start_xla_profiler
-        profiling = start_xla_profiler(args.xla_profile)
+        start_xla_profiler(args.xla_profile)
     t0 = time.time()
     # the flush lives in a finally: a Ctrl-C (or a mid-run engine error)
     # must still leave parseable --metrics-out/--trace-out files behind —
@@ -290,9 +319,9 @@ def main(argv=None):
     finally:
         results = dict(eng.results)
         dt = time.time() - t0
-        if profiling:
+        if args.xla_profile:
             from repro.serving.telemetry import stop_xla_profiler
-            stop_xla_profiler(profiling)
+            stop_xla_profiler()
             log.info("wrote jax.profiler device trace to %s",
                      args.xla_profile)
         _flush_telemetry(args, telemetry)
@@ -313,5 +342,11 @@ def main(argv=None):
     return results
 
 
+def main(argv=None):
+    return drive(*build(argv))
+
+
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

@@ -7,7 +7,8 @@ On this CPU container it runs real steps on reduced configs (--smoke) or
 full configs at your peril; on a TPU pod the same entry point picks up the
 production mesh. Composes: data pipeline -> sharded train step ->
 fault-tolerance supervisor (SIGTERM drain, retries, straggler watchdog) ->
-async checkpointing with elastic restore.
+async checkpointing with elastic restore. Run as a module, it keeps
+XLA's persistent compilation cache where launch/compile_cache.py says.
 """
 
 from __future__ import annotations
@@ -131,4 +132,6 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

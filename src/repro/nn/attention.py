@@ -24,7 +24,8 @@ Backends (``resolve_attn_impl``):
                    score matrix never exceeds one (q_block, kv_block) tile
   "pallas_flash"   the Pallas flash kernel (TPU; interpret=True on CPU)
   "auto"           xla_ref on CPU (bit-compatible with the historical
-                   path), pallas_flash on accelerators for prefill/cross;
+                   path) and under a multi-device mesh, pallas_flash on
+                   one accelerator for prefill/cross;
                    decode always resolves to xla_ref (a single-query
                    attend is already O(T) with no score blowup)
 
@@ -75,6 +76,8 @@ def resolve_attn_impl(impl: str = "auto", *, family: str = "prefill") -> str:
     On CPU auto also stays on xla_ref for prefill — it is bit-identical to
     the pre-flash behaviour (tests and the serving parity suite depend on
     that); the blockwise paths remain selectable explicitly everywhere.
+    Under a multi-device mesh it stays on xla_ref too: GSPMD partitions
+    that, not a Pallas kernel.
     """
     if impl not in ATTN_IMPLS:
         raise ValueError(f"unknown attn impl {impl!r}; known: {ATTN_IMPLS}")
@@ -82,8 +85,10 @@ def resolve_attn_impl(impl: str = "auto", *, family: str = "prefill") -> str:
         return impl
     if family == "decode":
         return "xla_ref"
-    on_cpu = jax.default_backend() == "cpu"
-    return "xla_ref" if on_cpu else "pallas_flash"
+    from repro.distributed.sharding import sharded_mesh_active
+    if jax.default_backend() == "cpu" or sharded_mesh_active():
+        return "xla_ref"
+    return "pallas_flash"
 
 
 # ---------------------------------------------------------------------------
@@ -294,11 +299,8 @@ def resolve_cache_update(method: str = "auto") -> str:
     sharded inputs do, since jit cache keys include input shardings."""
     if method != "auto":
         return method
-    from repro.distributed.sharding import active_mesh
-    mesh = active_mesh()
-    if mesh is not None and getattr(mesh, "size", 1) > 1:
-        return "mask"
-    return "dus"
+    from repro.distributed.sharding import sharded_mesh_active
+    return "mask" if sharded_mesh_active() else "dus"
 
 
 def cache_update_decode(cache, k_new, v_new, *, method: str = "auto"):

@@ -73,6 +73,7 @@ stays the cheap always-on dict; ``STATS_SCHEMA`` documents its keys.
 from __future__ import annotations
 
 import dataclasses
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -284,8 +285,8 @@ class ServeEngine:
             # succeed once refcount-0 tree blocks are evicted
             self.n_blocks = (n_blocks if n_blocks is not None
                              else max_batch * self.n_pages)
-            self.caches = api.init_paged_cache(self.n_blocks, bs,
-                                               max_batch, self.n_pages)
+            init_pool = partial(api.init_paged_cache, self.n_blocks, bs,
+                                max_batch, self.n_pages)
             self.pool = PrefixPool(self.n_blocks, bs, metrics=_reg)
             self._pstate: dict[int, _PagedSlot] = {}
             self._codec = kvc.get_codec(api.cfg.kv_cache)
@@ -293,15 +294,19 @@ class ServeEngine:
                                      np.int32)
         else:
             self.pool_len = max_len
-            self.caches = api.init_cache(max_batch, max_len)
-        if mesh is not None:
+            init_pool = partial(api.init_cache, max_batch, max_len)
+        if mesh is None:
+            self.caches = init_pool()
+        else:
             # the pool itself and every transient prefill cache carry
-            # NamedShardings with the head axis on "model": device_put here,
-            # out_shardings on every jit that returns a pool below — cache
-            # blocks never gather to one device between the two
-            self._cache_sh = kvc.cache_shardings(self.caches, mesh,
-                                                 self._mesh_rules)
-            self.caches = jax.device_put(self.caches, self._cache_sh)
+            # NamedShardings with the head axis on "model": the pool is
+            # created sharded (never whole on one device), and every jit
+            # that returns a pool below pins out_shardings — cache blocks
+            # never gather to one device
+            self._cache_sh = kvc.cache_shardings(
+                jax.eval_shape(init_pool), mesh, self._mesh_rules)
+            self.caches = jax.jit(init_pool,
+                                  out_shardings=self._cache_sh)()
             self._prefill_sh = kvc.cache_shardings(
                 jax.eval_shape(
                     lambda: api.init_cache(max_batch, self.pool_len)),
@@ -1154,6 +1159,15 @@ class ServeEngine:
         while self.step():
             pass
         return dict(self.results)
+
+    def prefill_logits(self, tokens, seq_lens) -> jax.Array:
+        """Last-token logits of one blocking prefill of right-padded
+        ``tokens`` (B, S) through this engine's own jitted (and, under a
+        mesh, sharded) prefill step. Pool, slots and queue are untouched:
+        a parity probe across engines, not an admission."""
+        logits, _ = self._prefill(self.params, jnp.asarray(tokens),
+                                  jnp.asarray(seq_lens))
+        return logits
 
     def utilization(self) -> float:
         """Mean fraction of occupied slots per decode step."""

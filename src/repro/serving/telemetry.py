@@ -28,8 +28,8 @@ token-identical with an equal jitted-dispatch count.
 
 The opt-in exception is :func:`start_xla_profiler` — an explicit request
 for a *device* trace (``jax.profiler``), which is jax's machinery, not
-this module's bookkeeping, and degrades to a one-time warning on backends
-without profiler support.
+this module's bookkeeping. A profiler that was asked for and fails to
+start is an error, never a silent run without a device trace.
 """
 
 from __future__ import annotations
@@ -37,7 +37,6 @@ from __future__ import annotations
 import json
 import threading
 import time
-import warnings
 
 
 # ---------------------------------------------------------------------------
@@ -524,34 +523,17 @@ class Telemetry:
 # device-trace hook (opt-in; the one place jax enters this module)
 # ---------------------------------------------------------------------------
 
-_profiler_warned = False
-
-
-def start_xla_profiler(logdir: str) -> bool:
+def start_xla_profiler(logdir: str):
     """Start a ``jax.profiler`` device trace into ``logdir``.
 
-    Returns True when the trace started. On backends without profiler
-    support (or any start failure) this warns ONCE per process and
-    returns False — a missing profiler must never take the serve loop
-    down with it.
+    A failure to start propagates: the trace was requested explicitly, and
+    a run that quietly records none reads later as one with no device
+    work at all.
     """
-    global _profiler_warned
-    try:
-        import jax
-        jax.profiler.start_trace(logdir)
-        return True
-    except Exception as e:  # noqa: BLE001 - backend-dependent failure set
-        if not _profiler_warned:
-            _profiler_warned = True
-            warnings.warn(
-                f"--xla-profile requested but the device profiler is "
-                f"unavailable on this backend ({e!r}); serving continues "
-                "without a device trace", RuntimeWarning, stacklevel=2)
-        return False
+    import jax
+    jax.profiler.start_trace(logdir)
 
 
-def stop_xla_profiler(started: bool):
-    if not started:
-        return
+def stop_xla_profiler():
     import jax
     jax.profiler.stop_trace()

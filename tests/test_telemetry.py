@@ -4,7 +4,6 @@ the load-bearing one — the overhead contract: telemetry on vs. off is
 token-identical with an equal jitted-dispatch count."""
 
 import json
-import warnings
 
 import jax
 import numpy as np
@@ -419,17 +418,16 @@ def test_ratios_guarded_before_first_tick(model):
 
 
 # ---------------------------------------------------------------------------
-# device-profiler hook degrades to a single warning
+# device-profiler hook: an explicit request that fails is an error
 # ---------------------------------------------------------------------------
 
 def test_xla_profiler_warns_once_and_keeps_serving(monkeypatch):
+    """--xla-profile was asked for: a profiler that cannot start raises
+    (every time, not once) instead of warning and serving on without the
+    device trace."""
     monkeypatch.setattr(jax.profiler, "start_trace",
                         lambda *a, **k: (_ for _ in ()).throw(
                             RuntimeError("no profiler on this backend")))
-    monkeypatch.setattr(T, "_profiler_warned", False)
-    with pytest.warns(RuntimeWarning, match="profiler is unavailable"):
-        assert T.start_xla_profiler("/tmp/nowhere") is False
-    with warnings.catch_warnings():
-        warnings.simplefilter("error")       # a second warning would raise
-        assert T.start_xla_profiler("/tmp/nowhere") is False
-    T.stop_xla_profiler(False)               # not-started stop is a no-op
+    for _ in range(2):
+        with pytest.raises(RuntimeError, match="no profiler"):
+            T.start_xla_profiler("/tmp/nowhere")
