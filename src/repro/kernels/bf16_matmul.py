@@ -49,4 +49,5 @@ def bf16_matmul_pallas(a: jax.Array, w: jax.Array, *, bm: int = 256,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, s: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
         interpret=interpret,
+        name="bf16_matmul_pallas",
     )(a.astype(jnp.bfloat16), w.astype(jnp.bfloat16))

@@ -76,6 +76,7 @@ def binary_matmul_pallas(pa: jax.Array, pw: jax.Array, *, k: int,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, s: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.int32),
         interpret=interpret,
+        name="binary_matmul_pallas",
     )(pa, pw)
 
 

@@ -143,6 +143,7 @@ def _flash_call(qt, kt, vt, kvlen, *, causal, scale, q_offset, bq, bk,
             ]),
         out_shape=jax.ShapeDtypeStruct((b, hq, sp, dv), vt.dtype),
         interpret=interpret,
+        name="_flash_call",
     )(kvlen, qt, kt, vt)
 
 

@@ -63,5 +63,6 @@ def hybrid_dense_pallas(pa: jax.Array, pw: jax.Array, scale: jax.Array,
         out_specs=pl.BlockSpec((bm, n // LANE_BITS), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((m, n // LANE_BITS), jnp.uint32),
         interpret=interpret,
+        name="hybrid_dense_pallas",
     )(pa, pw, scale.reshape(1, n).astype(jnp.float32),
       shift.reshape(1, n).astype(jnp.float32))

@@ -83,5 +83,6 @@ def int8_matmul_pallas(a: jax.Array, pw: jax.Array, *, bm: int = 256,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, s: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, n), jnp.int32),
         interpret=interpret,
+        name="int8_matmul_pallas",
     )(a_bits, pw)
     return out[:m]

@@ -176,6 +176,7 @@ def _quant_int8_call(x2, *, bn, interpret):
         out_shape=[jax.ShapeDtypeStruct((n, d), jnp.int8),
                    jax.ShapeDtypeStruct((n, 1), jnp.bfloat16)],
         interpret=interpret,
+        name="_quant_int8_call",
     )(x2)
 
 
@@ -193,6 +194,7 @@ def _dequant_int8_call(v2, s2, *, bn, interpret):
         out_specs=pl.BlockSpec((bn, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n, d), jnp.float32),
         interpret=interpret,
+        name="_dequant_int8_call",
     )(v2, s2)
 
 
@@ -210,6 +212,7 @@ def _quant_binary_call(x2, *, bn, interpret):
         out_shape=[jax.ShapeDtypeStruct((n, kp), jnp.uint32),
                    jax.ShapeDtypeStruct((n, 1), jnp.bfloat16)],
         interpret=interpret,
+        name="_quant_binary_call",
     )(x2)
 
 
@@ -225,6 +228,7 @@ def _dequant_binary_call(p2, s2, *, d, bn, interpret):
         out_specs=pl.BlockSpec((bn, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n, d), jnp.float32),
         interpret=interpret,
+        name="_dequant_binary_call",
     )(p2, s2)
 
 

@@ -58,30 +58,41 @@ def resolve_impl(mode: str, impl: str = "auto") -> str:
     raise ValueError(f"unknown binary mode {mode!r}")
 
 
+# latent weight (K, N) -> the packed operand each lowering multiplies by
+_WEIGHT_PACK = {
+    "bf16": lambda w: jnp.where(w >= 0, 1.0, -1.0).astype(jnp.bfloat16),
+    "xla_xnor": lambda w: pack_bits(w.T),
+    "pallas_xnor": lambda w: pack_bits(w.T),
+    "xla_int8": lambda w: pack_signs_int8(w.T),
+    "pallas_int8": lambda w: pack_bits(w.T),
+}
+
+
 def _binary_matmul_fwd(x2d: jax.Array, w: jax.Array, impl: str) -> jax.Array:
     """x2d (M, K), w (K, N) latent -> (M, N) in x2d's dtype (integer-valued;
     bf16 IO keeps the TP all-reduce wire format narrow — see EXPERIMENTS.md
     section Perf, qwen3 H5; |dot| <= K so bf16 rounds above 256 by <0.4%)."""
     k = x2d.shape[-1]
     out_dtype = x2d.dtype
+    if impl not in _WEIGHT_PACK:
+        raise ValueError(impl)
+    # the weight-side sign-and-pack, redone every call from the latent
+    # weights; its own scope lets a device trace measure it
+    with jax.named_scope("ffn_pack"):
+        wq = _WEIGHT_PACK[impl](w)
     if impl == "bf16":
         y = kref.bf16_matmul_ref(
-            jnp.where(x2d >= 0, 1.0, -1.0).astype(jnp.bfloat16),
-            jnp.where(w >= 0, 1.0, -1.0).astype(jnp.bfloat16))
+            jnp.where(x2d >= 0, 1.0, -1.0).astype(jnp.bfloat16), wq)
     elif impl == "xla_xnor":
-        y = kref.binary_matmul_packed_ref(pack_bits(x2d), pack_bits(w.T), k)
+        y = kref.binary_matmul_packed_ref(pack_bits(x2d), wq, k)
     elif impl == "pallas_xnor":
         interp = jax.default_backend() == "cpu"
-        y = binary_matmul_pallas(pack_bits(x2d), pack_bits(w.T), k=k,
-                                 interpret=interp)
+        y = binary_matmul_pallas(pack_bits(x2d), wq, k=k, interpret=interp)
     elif impl == "xla_int8":
-        y = kref.int8_matmul_ref(pack_signs_int8(x2d), pack_signs_int8(w.T))
-    elif impl == "pallas_int8":
+        y = kref.int8_matmul_ref(pack_signs_int8(x2d), wq)
+    else:                                   # pallas_int8
         interp = jax.default_backend() == "cpu"
-        y = int8_matmul_pallas(pack_signs_int8(x2d), pack_bits(w.T),
-                               interpret=interp)
-    else:
-        raise ValueError(impl)
+        y = int8_matmul_pallas(pack_signs_int8(x2d), wq, interpret=interp)
     return y.astype(out_dtype)
 
 
@@ -132,7 +143,8 @@ def binary_dense(x: jax.Array, w_latent: jax.Array, *, mode: str = "xnor",
 def _binary_matmul_batched_fwd(x3, w3, impl):
     if impl in ("bf16",):
         sx = jnp.where(x3 >= 0, 1.0, -1.0).astype(jnp.bfloat16)
-        sw = jnp.where(w3 >= 0, 1.0, -1.0).astype(jnp.bfloat16)
+        with jax.named_scope("ffn_pack"):
+            sw = jnp.where(w3 >= 0, 1.0, -1.0).astype(jnp.bfloat16)
         return jax.lax.dot_general(
             sx, sw, (((2,), (1,)), ((0,), (0,))),
             preferred_element_type=jnp.float32)
@@ -140,14 +152,16 @@ def _binary_matmul_batched_fwd(x3, w3, impl):
         # grouped int8 dot (pallas path would vmap the kernel; the XLA
         # batched dot is what GSPMD shards over the expert axis)
         sx = pack_signs_int8(x3)
-        sw = pack_signs_int8(w3)
+        with jax.named_scope("ffn_pack"):
+            sw = pack_signs_int8(w3)
         return jax.lax.dot_general(
             sx, sw, (((2,), (1,)), ((0,), (0,))),
             preferred_element_type=jnp.int32).astype(jnp.float32)
     if impl in ("xla_xnor", "pallas_xnor"):
         k = x3.shape[-1]
         pa = pack_bits(x3)                       # (G, M, Kp)
-        pw = pack_bits(jnp.swapaxes(w3, 1, 2))   # (G, N, Kp)
+        with jax.named_scope("ffn_pack"):
+            pw = pack_bits(jnp.swapaxes(w3, 1, 2))   # (G, N, Kp)
         x = jnp.bitwise_xor(pa[:, :, None, :], pw[:, None, :, :])
         pc = jnp.sum(jax.lax.population_count(x).astype(jnp.int32), axis=-1)
         return (jnp.int32(k) - 2 * pc).astype(jnp.float32)

@@ -153,12 +153,15 @@ def gqa_decode(p, x, cfg: ModelConfig, cache):
     positions = cache["len"][:, None]  # (B, 1)
     q, k, v = gqa_qkv(p, x, cfg, positions)
     codec = kvc.get_codec(cfg.kv_cache)
-    if "table" in cache:
-        cache = kvc.paged_insert_timestep(cache, k, v, codec)
-        o = kvc.paged_decode_attention(q, cache, codec)
-    else:
-        cache = codec.insert_timestep(cache, k, v, method=cfg.cache_update)
-        o = codec.decode_attention(q, cache, impl=cfg.attn_impl)
+    # named scopes (op metadata only) let a device trace charge the pool's
+    # writes and the attention reads to their layers
+    paged = "table" in cache
+    with jax.named_scope("kv_pool"):
+        cache = (kvc.paged_insert_timestep(cache, k, v, codec) if paged else
+                 codec.insert_timestep(cache, k, v, method=cfg.cache_update))
+    with jax.named_scope("attn"):
+        o = (kvc.paged_decode_attention(q, cache, codec) if paged else
+             codec.decode_attention(q, cache, impl=cfg.attn_impl))
     o = o.reshape(*x.shape[:2], -1)
     return nn.dense_apply(p["wo"], o, compute_dtype=cdt(cfg),
                           binary_impl=cfg.spec_draft_impl), cache
@@ -180,12 +183,13 @@ def gqa_verify(p, x, cfg: ModelConfig, cache):
     q, k, v = gqa_qkv(p, x, cfg, positions)
     codec = kvc.get_codec(cfg.kv_cache)
     q_lens = positions + 1
-    if "table" in cache:
-        cache = kvc.paged_insert_span(cache, k, v, codec)
-        o = kvc.paged_decode_attention(q, cache, codec, q_lens=q_lens)
-    else:
-        cache = codec.insert_span(cache, k, v, method=cfg.cache_update)
-        o = codec.decode_attention(q, cache, q_lens=q_lens)
+    paged = "table" in cache
+    with jax.named_scope("kv_pool"):
+        cache = (kvc.paged_insert_span(cache, k, v, codec) if paged else
+                 codec.insert_span(cache, k, v, method=cfg.cache_update))
+    with jax.named_scope("attn"):
+        o = (kvc.paged_decode_attention(q, cache, codec, q_lens=q_lens)
+             if paged else codec.decode_attention(q, cache, q_lens=q_lens))
     o = o.reshape(*x.shape[:2], -1)
     return nn.dense_apply(p["wo"], o, compute_dtype=cdt(cfg),
                           binary_impl=cfg.spec_draft_impl), cache
@@ -430,21 +434,23 @@ def block_prefill(p, x, cfg: ModelConfig, sig: BlockSig, *, positions,
                  "len": jnp.full((b,), s, jnp.int32)}
     else:
         q, k, v = gqa_qkv(p["attn"], h, cfg, positions)
-        if ctx is not None:
-            o = attn_lib.prefix_prefill_attention(q, ctx["k"], ctx["v"],
-                                                  ctx_len, k, v,
-                                                  kv_len=seq_lens)
-        else:
-            o = attn_lib.prefill_attention(q, k, v, chunk=cfg.attn_chunk,
-                                           kv_len=seq_lens,
-                                           impl=cfg.attn_impl)
+        with jax.named_scope("attn"):
+            if ctx is not None:
+                o = attn_lib.prefix_prefill_attention(q, ctx["k"], ctx["v"],
+                                                      ctx_len, k, v,
+                                                      kv_len=seq_lens)
+            else:
+                o = attn_lib.prefill_attention(q, k, v, chunk=cfg.attn_chunk,
+                                               kv_len=seq_lens,
+                                               impl=cfg.attn_impl)
         a = nn.dense_apply(p["attn"]["wo"], o.reshape(b, s, -1),
                            compute_dtype=cdt(cfg),
                            binary_impl=cfg.spec_draft_impl)
         # encode k/v into the configured cache codec (bf16 layout for
         # "auto"; int8/binary quantize at prefill time so the pool never
         # holds a dense bf16 copy)
-        cache = kvc.get_codec(cfg.kv_cache).from_prefill(k, v, max_len)
+        with jax.named_scope("kv_pool"):
+            cache = kvc.get_codec(cfg.kv_cache).from_prefill(k, v, max_len)
     x = x + a
     h = nn.rmsnorm_apply(p["ln2"], x)
     if sig.moe:

@@ -63,7 +63,12 @@ Telemetry (``telemetry=``, serving/telemetry.py) threads a metrics
 registry + lifecycle tracer through every path above: request spans
 (queued -> admitted -> first token -> generate -> finished), per-phase
 tick histograms (prefill wave / decode tick / spec wave), queue-wait,
-TTFT/ITL, and cache-pressure gauges. The contract is **zero extra device
+TTFT/ITL, and cache-pressure gauges. Each tick's phases (``serve.step``
+around ``serve.admit`` / ``serve.dispatch`` / ``serve.sync`` /
+``serve.emit``) are profiler spans on the device trace's clock, and each
+jitted entry has a stable name (``serve_decode``, ``serve_prefill``,
+``serve_insert``, ``serve_spec_wave``, ``serve_prefill_slice``) that
+names its module in the trace. The contract is **zero extra device
 work**: every hook reads host clocks and host integers the engine already
 holds, so telemetry on vs. off is token-identical with an equal
 jitted-dispatch count (tests/test_telemetry.py asserts both). ``stats``
@@ -72,8 +77,9 @@ stays the cheap always-on dict; ``STATS_SCHEMA`` documents its keys.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
-from functools import partial
+from functools import partial, wraps
 
 import jax
 import jax.numpy as jnp
@@ -89,6 +95,19 @@ from repro.serving.scheduler import (AdmissionError, FifoScheduler, Request,
                                      make_buckets, pad_group, slo_rank)
 
 log = logging.getLogger("repro.serving.engine")
+
+# the phase span of an engine without telemetry: none at all
+_NO_PHASE = contextlib.nullcontext()
+
+
+def _entry(name: str, fn):
+    """``fn`` under a stable name: jit names its module after it, so the
+    device trace says which engine entry ran."""
+    @wraps(fn)
+    def entry(*args):
+        return fn(*args)
+    entry.__name__ = entry.__qualname__ = name
+    return entry
 
 
 # every ServeEngine.stats key, its type, and what it counts — the schema
@@ -360,7 +379,7 @@ class ServeEngine:
         # self.caches, so XLA can update the (layers, B, T, ...) buffers in
         # place instead of copying the whole pool every tick
         self._decode = self._meshed(jax.jit(
-            api.decode, donate_argnums=1,
+            _entry("serve_decode", api.decode), donate_argnums=1,
             **outs(self._repl, self._cache_sh) if mesh is not None
             else {}))
         prefill_fn = api.prefill_chunked if self.prefill_chunk else \
@@ -368,14 +387,17 @@ class ServeEngine:
         prefill_kw = ({"chunk": self.prefill_chunk} if self.prefill_chunk
                       else {})
         self._prefill = self._meshed(jax.jit(
-            lambda p, toks, sl: prefill_fn(p, {"tokens": toks},
-                                           max_len=self.pool_len,
-                                           seq_lens=sl, **prefill_kw),
+            _entry("serve_prefill",
+                   lambda p, toks, sl: prefill_fn(p, {"tokens": toks},
+                                                  max_len=self.pool_len,
+                                                  seq_lens=sl,
+                                                  **prefill_kw)),
             **outs(self._repl, self._prefill_sh) if mesh is not None
             else {}))
         if self.paged:
             self._insert_pages = self._meshed(jax.jit(
-                kvc.paged_insert_prefill, donate_argnums=0,
+                _entry("serve_insert", kvc.paged_insert_prefill),
+                donate_argnums=0,
                 **outs(self._cache_sh) if mesh is not None else {}))
             self._update_slots = self._meshed(jax.jit(
                 kvc.paged_update_slots, donate_argnums=0,
@@ -390,14 +412,15 @@ class ServeEngine:
                     seq_lens=sl)))
         else:
             self._insert = self._meshed(jax.jit(
-                api.cache_insert, donate_argnums=0,
+                _entry("serve_insert", api.cache_insert), donate_argnums=0,
                 **outs(self._cache_sh) if mesh is not None else {}))
         if self.interleave:
             # one slice per tick: exact K/V appends into the job's
             # transient cache (donated — updated in place across slices),
             # last-token hidden capture, head matmul deferred to finish
             self._slice = self._meshed(jax.jit(
-                api.prefill_slice, donate_argnums=(1, 3),
+                _entry("serve_prefill_slice", api.prefill_slice),
+                donate_argnums=(1, 3),
                 **outs(self._repl, self._prefill_sh) if mesh is not None
                 else {}))
             self._slice_finish = self._meshed(jax.jit(
@@ -444,9 +467,10 @@ class ServeEngine:
             # round-trip in between: 2k+3 dispatches per wave, and the
             # dispatch overhead is what kept hybrid at 0.4x wall-clock)
             self._spec_wave = self._meshed(jax.jit(
-                make_spec_wave(api, k=self.spec_k,
-                               temperature=float(temperature),
-                               seed_key=self._seed_key),
+                _entry("serve_spec_wave",
+                       make_spec_wave(api, k=self.spec_k,
+                                      temperature=float(temperature),
+                                      seed_key=self._seed_key)),
                 donate_argnums=2,
                 **outs(self._repl, self._repl, self._cache_sh)
                 if mesh is not None else {}))
@@ -479,6 +503,11 @@ class ServeEngine:
             finally:
                 shd.set_logical_rules(*prev)
         return call
+
+    def _phase(self, name: str):
+        """The telemetry phase span ``name`` (``Telemetry.phase``); none
+        without telemetry."""
+        return self.tm.phase(name) if self.tm is not None else _NO_PHASE
 
     def check_request(self, prompt_len: int, max_new: int,
                       slo: str = "standard") -> None:
@@ -547,14 +576,16 @@ class ServeEngine:
         serving/spec.make_spec_wave — with the same per-row streams.)
         """
         if self.temperature <= 0:
-            return np.asarray(jnp.argmax(logits, axis=-1).astype(jnp.int32))
-        rids = np.asarray([r.rid if r is not None else 0 for r in reqs],
-                          np.int32)
-        steps = np.asarray([len(r.out) if r is not None else 0
-                            for r in reqs], np.int32)
-        return np.asarray(self._sample_rows(jnp.asarray(rids),
-                                            jnp.asarray(steps), logits,
-                                            float(self.temperature)))
+            toks = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        else:
+            rids = np.asarray([r.rid if r is not None else 0 for r in reqs],
+                              np.int32)
+            steps = np.asarray([len(r.out) if r is not None else 0
+                                for r in reqs], np.int32)
+            toks = self._sample_rows(jnp.asarray(rids), jnp.asarray(steps),
+                                     logits, float(self.temperature))
+        with self._phase("serve.sync"):
+            return np.asarray(toks)
 
     # -- slot lifecycle -----------------------------------------------------
 
@@ -630,7 +661,8 @@ class ServeEngine:
         # dummy rows aim past the pool and are dropped by the scatter
         idx = np.full((gp,), self.max_batch, np.int32)
         idx[:len(group)] = free[:len(group)]
-        self.caches = self._insert(self.caches, new, jnp.asarray(idx))
+        with self._phase("serve.dispatch"):
+            self.caches = self._insert(self.caches, new, jnp.asarray(idx))
         self.stats["prefills"] += 1
         now = 0.0
         if self.tm is not None:
@@ -638,17 +670,18 @@ class ServeEngine:
             if wave_t0 is not None:
                 self.tm.prefill_wave(wave_t0, n_reqs=len(group),
                                      bucket=blen, now=now)
-        for j, r in enumerate(group):
-            slot = int(idx[j])
-            self.slots[slot] = r
-            self.stats["admitted"] += 1
-            self.stats["prefilled_tokens"] += len(r.prompt)
-            if self.tm is not None:
-                self.tm.request_admitted(
-                    r.rid, slot=slot, prefilled_tokens=len(r.prompt),
-                    now=t_admit)
-                self.tm.tokens_emitted(r.rid, 1, now=now)
-            self._append_token(slot, int(nxt[j]))
+        with self._phase("serve.emit"):
+            for j, r in enumerate(group):
+                slot = int(idx[j])
+                self.slots[slot] = r
+                self.stats["admitted"] += 1
+                self.stats["prefilled_tokens"] += len(r.prompt)
+                if self.tm is not None:
+                    self.tm.request_admitted(
+                        r.rid, slot=slot, prefilled_tokens=len(r.prompt),
+                        now=t_admit)
+                    self.tm.tokens_emitted(r.rid, 1, now=now)
+                self._append_token(slot, int(nxt[j]))
 
     def _admit(self):
         """Prefill queued requests into free slots (one group per bucket)."""
@@ -665,8 +698,9 @@ class ServeEngine:
                 self.queue.remove(r)
             toks, lens, blen, gp = self._group_arrays(group)
             t0 = self.tm.clock() if self.tm is not None else 0.0
-            logits, new = self._prefill(self.params, jnp.asarray(toks),
-                                        jnp.asarray(lens))
+            with self._phase("serve.dispatch"):
+                logits, new = self._prefill(self.params, jnp.asarray(toks),
+                                            jnp.asarray(lens))
             self._install_contig(group, blen, gp, logits, new,
                                  wave_t0=t0, t_admit=t0)
             free = [i for i, r in enumerate(self.slots) if r is None]
@@ -777,13 +811,14 @@ class ServeEngine:
 
     def _paged_prefill_call(self, a: dict):
         """One blocking suffix prefill (plain, or against gathered ctx)."""
-        if a["max_ctx_pages"] == 0:
-            return self._prefill(self.params, jnp.asarray(a["toks"]),
-                                 jnp.asarray(a["lens"]))
-        ctx = self._gather_ctx(self.caches, jnp.asarray(a["ctx_tab"]))
-        return self._prefill_ctx(self.params, jnp.asarray(a["toks"]),
-                                 jnp.asarray(a["lens"]), ctx,
-                                 jnp.asarray(a["ctx_lens"]))
+        with self._phase("serve.dispatch"):
+            if a["max_ctx_pages"] == 0:
+                return self._prefill(self.params, jnp.asarray(a["toks"]),
+                                     jnp.asarray(a["lens"]))
+            ctx = self._gather_ctx(self.caches, jnp.asarray(a["ctx_tab"]))
+            return self._prefill_ctx(self.params, jnp.asarray(a["toks"]),
+                                     jnp.asarray(a["lens"]), ctx,
+                                     jnp.asarray(a["ctx_lens"]))
 
     def _install_paged(self, admitted, a: dict, logits, new, *,
                        wave_t0=None, t_admit=0.0):
@@ -799,17 +834,18 @@ class ServeEngine:
         gp = a["gp"]
         row_reqs = list(group) + [None] * (gp - len(group))
         nxt = self._sample(logits, row_reqs)
-        self.caches = self._insert_pages(self.caches, new,
-                                         jnp.asarray(a["dest"]))
         # padded to the group's power-of-two size like every other
         # admission op (one compile per log group size, not per size);
         # dummy rows aim past the pool and drop
         slot_idx = np.full((gp,), self.max_batch, np.int32)
         slot_idx[:len(group)] = slots
-        self.caches = self._update_slots(self.caches,
-                                         jnp.asarray(a["rows"]),
-                                         jnp.asarray(a["plens"]),
-                                         jnp.asarray(slot_idx))
+        with self._phase("serve.dispatch"):
+            self.caches = self._insert_pages(self.caches, new,
+                                             jnp.asarray(a["dest"]))
+            self.caches = self._update_slots(self.caches,
+                                             jnp.asarray(a["rows"]),
+                                             jnp.asarray(a["plens"]),
+                                             jnp.asarray(slot_idx))
         self.stats["prefills"] += 1
         now = 0.0
         if self.tm is not None:
@@ -817,28 +853,31 @@ class ServeEngine:
             if wave_t0 is not None:
                 self.tm.prefill_wave(wave_t0, n_reqs=len(group),
                                      bucket=a["blen"], now=now)
-        for j, (r, chain, blocks) in enumerate(admitted):
-            slot = slots[j]
-            self.slots[slot] = r
-            st = _PagedSlot(plen=len(r.prompt), row=a["rows"][j],
-                            chain=chain, private=list(blocks))
-            self._pstate[slot] = st
-            self.stats["admitted"] += 1
-            self.stats["prefilled_tokens"] += int(a["lens"][j])
-            self.stats["cached_prompt_tokens"] += int(a["ctx_lens"][j])
-            if self.tm is not None:
-                self.tm.request_admitted(
-                    r.rid, slot=slot, prefilled_tokens=int(a["lens"][j]),
-                    cached_tokens=int(a["ctx_lens"][j]), now=t_admit)
-                self.tm.tokens_emitted(r.rid, 1, now=now)
-            self.pool.record_hit(chain)
-            if self.prefix_on:
-                # publish the prompt's full blocks beyond the matched
-                # prefix, so requests admitted from the next wave on share
-                # them (same-wave requests prefilled independently)
-                for pi in range(len(chain), len(r.prompt) // bs):
-                    self._publish_block(st, pi, r)
-            self._append_token(slot, int(nxt[j]))
+        with self._phase("serve.emit"):
+            for j, (r, chain, blocks) in enumerate(admitted):
+                slot = slots[j]
+                self.slots[slot] = r
+                st = _PagedSlot(plen=len(r.prompt), row=a["rows"][j],
+                                chain=chain, private=list(blocks))
+                self._pstate[slot] = st
+                self.stats["admitted"] += 1
+                self.stats["prefilled_tokens"] += int(a["lens"][j])
+                self.stats["cached_prompt_tokens"] += int(a["ctx_lens"][j])
+                if self.tm is not None:
+                    self.tm.request_admitted(
+                        r.rid, slot=slot,
+                        prefilled_tokens=int(a["lens"][j]),
+                        cached_tokens=int(a["ctx_lens"][j]), now=t_admit)
+                    self.tm.tokens_emitted(r.rid, 1, now=now)
+                self.pool.record_hit(chain)
+                if self.prefix_on:
+                    # publish the prompt's full blocks beyond the matched
+                    # prefix, so requests admitted from the next wave on
+                    # share them (same-wave requests prefilled
+                    # independently)
+                    for pi in range(len(chain), len(r.prompt) // bs):
+                        self._publish_block(st, pi, r)
+                self._append_token(slot, int(nxt[j]))
 
     def _publish_block(self, st: _PagedSlot, pi: int, r: Request):
         """Hang slot page pi (now full and immutable) on the radix tree."""
@@ -924,13 +963,14 @@ class ServeEngine:
                                 wave_t0=None, t_admit=job.t_start)
             return True
         t0 = self.tm.clock() if self.tm is not None else 0.0
-        if job.caches is None:
-            job.caches, job.h_last = self._job_init(job.gp)
         c = min(self.slice_chunk, job.blen)
-        job.h_last, job.caches = self._slice(
-            self.params, job.caches,
-            jnp.asarray(job.toks[:, job.pos:job.pos + c]), job.h_last,
-            jnp.asarray(job.lens), jnp.asarray(job.pos, jnp.int32))
+        with self._phase("serve.dispatch"):
+            if job.caches is None:
+                job.caches, job.h_last = self._job_init(job.gp)
+            job.h_last, job.caches = self._slice(
+                self.params, job.caches,
+                jnp.asarray(job.toks[:, job.pos:job.pos + c]), job.h_last,
+                jnp.asarray(job.lens), jnp.asarray(job.pos, jnp.int32))
         job.pos += c
         self.stats["prefill_slices"] += 1
         if self.tm is not None:
@@ -938,9 +978,10 @@ class ServeEngine:
                                   tokens=c * job.gp, bucket=job.blen)
         if job.pos < job.todo:
             return False
-        logits, new = self._slice_finish(self.params, job.caches,
-                                         job.h_last,
-                                         jnp.asarray(job.lens))
+        with self._phase("serve.dispatch"):
+            logits, new = self._slice_finish(self.params, job.caches,
+                                             job.h_last,
+                                             jnp.asarray(job.lens))
         if self.paged:
             self._install_paged(job.admitted, job.arrays, logits, new,
                                 wave_t0=None, t_admit=job.t_start)
@@ -976,12 +1017,20 @@ class ServeEngine:
         """One tick: admit into free slots, then one batched decode step over
         the full pool (or one draft/verify wave with spec_k > 0). Returns
         False once no slot is occupied (idle)."""
-        if self.spec_k:
-            return self._step_spec()
-        if self.interleave:
-            self._prefill_tick()
-        else:
-            self._admit()
+        if self.tm is None:
+            return self._tick()
+        with self.tm.phase("serve.step"):
+            busy = self._tick()
+        self.tm.tick_done(busy)
+        return busy
+
+    def _tick(self) -> bool:
+        """step() without its serve.step span."""
+        with self._phase("serve.admit"):
+            if self.interleave:
+                self._prefill_tick()
+            else:
+                self._admit()
         active = [i for i, r in enumerate(self.slots) if r is not None]
         if not active:
             if self._jobs:
@@ -991,54 +1040,55 @@ class ServeEngine:
                 self.step_count += 1
                 return True
             return False
+        if self.spec_k:
+            self._step_spec(active)
+        else:
+            self._step_decode(active)
+        return True
+
+    def _step_decode(self, active: list):
+        """One batched decode step over the full pool."""
         t0 = self.tm.clock() if self.tm is not None else 0.0
-        logits, self.caches = self._decode(self.params, self.caches,
-                                           jnp.asarray(self.next_tok))
+        with self._phase("serve.dispatch"):
+            logits, self.caches = self._decode(self.params, self.caches,
+                                               jnp.asarray(self.next_tok))
         nxt = self._sample(logits, list(self.slots))
         self.step_count += 1
         self.stats["decode_steps"] += 1
         self.stats["occupied_slot_steps"] += len(active)
-        now = 0.0
-        if self.tm is not None:
-            now = self.tm.clock()
-            self.tm.decode_tick(t0, n_active=len(active), now=now)
-        for i in active:
-            r = self.slots[i]
-            if self.paged and self.prefix_on:
-                # the decode just inserted K/V at position plen+len(out)-1;
-                # publish the block it completed, if any
-                st = self._pstate[i]
-                cur = st.plen + len(r.out)       # cache len after this tick
-                if cur % self.block_size == 0:
-                    self._publish_block(st, cur // self.block_size - 1, r)
+        with self._phase("serve.emit"):
+            now = 0.0
             if self.tm is not None:
-                self.tm.tokens_emitted(r.rid, 1, now=now)
-            self._append_token(i, int(nxt[i]))
-        if self.tm is not None:
-            self.tm.update_gauges(self._telemetry_gauges())
-        return True
+                now = self.tm.clock()
+                self.tm.decode_tick(t0, n_active=len(active), now=now)
+            for i in active:
+                r = self.slots[i]
+                if self.paged and self.prefix_on:
+                    # the decode just inserted K/V at position
+                    # plen+len(out)-1; publish the block it completed, if
+                    # any
+                    st = self._pstate[i]
+                    cur = st.plen + len(r.out)   # cache len after this tick
+                    if cur % self.block_size == 0:
+                        self._publish_block(st, cur // self.block_size - 1,
+                                            r)
+                if self.tm is not None:
+                    self.tm.tokens_emitted(r.rid, 1, now=now)
+                self._append_token(i, int(nxt[i]))
+            if self.tm is not None:
+                self.tm.update_gauges(self._telemetry_gauges())
 
-    def _step_spec(self) -> bool:
-        """One speculative wave: admit, draft spec_k tokens through the
-        binarized self-draft (sharing the target cache), verify all of
-        them plus the pending token in one float pass, and keep the
-        longest matching prefix + one correction/bonus token per slot.
+    def _step_spec(self, active: list):
+        """One speculative wave: draft spec_k tokens through the binarized
+        self-draft (sharing the target cache), verify all of them plus the
+        pending token in one float pass, and keep the longest matching
+        prefix + one correction/bonus token per slot.
 
         Token-identity with the plain engine holds by construction: the
         wave's j-th emission is drawn from *target* logits conditioned on
         an all-accepted history, using the request's own (rid, step)
         stream — the draft only decides how many of those emissions one
         wave can bank (1..spec_k+1 per slot)."""
-        if self.interleave:
-            self._prefill_tick()
-        else:
-            self._admit()
-        active = [i for i, r in enumerate(self.slots) if r is not None]
-        if not active:
-            if self._jobs:
-                self.step_count += 1
-                return True
-            return False
         k = self.spec_k
         # pre-wave cache length per slot (invariant: plen + len(out) - 1;
         # next_tok's K/V is not yet inserted). Free slots pin to 0 so
@@ -1058,12 +1108,14 @@ class ServeEngine:
         # positions with exact K/V, candidate selection from each
         # request's own (rid, step) stream
         t0 = self.tm.clock() if self.tm is not None else 0.0
-        tok_mat, cand, self.caches = self._spec_wave(
-            self.params, self.draft_params, self.caches,
-            jnp.asarray(self.next_tok), jnp.asarray(rids),
-            jnp.asarray(base_steps), jnp.asarray(base_len))
-        tok_mat = np.asarray(tok_mat)                   # (B, k+1)
-        cand = np.asarray(cand)                         # (B, k+1)
+        with self._phase("serve.dispatch"):
+            tok_mat, cand, self.caches = self._spec_wave(
+                self.params, self.draft_params, self.caches,
+                jnp.asarray(self.next_tok), jnp.asarray(rids),
+                jnp.asarray(base_steps), jnp.asarray(base_len))
+        with self._phase("serve.sync"):
+            tok_mat = np.asarray(tok_mat)               # (B, k+1)
+            cand = np.asarray(cand)                     # (B, k+1)
         self.stats["spec_draft_launches"] += 1
 
         # -- accept/reject (host): longest draft prefix matching the
@@ -1079,16 +1131,22 @@ class ServeEngine:
         # roll back before any bookkeeping: rejected positions fall past
         # len (and free slots to 0); paged _finish, which re-zeros its
         # slot, runs after this
-        self.caches = self._set_lens(self.caches, jnp.asarray(new_lens))
+        with self._phase("serve.dispatch"):
+            self.caches = self._set_lens(self.caches, jnp.asarray(new_lens))
         self.step_count += 1
         self.stats["decode_steps"] += 1
         self.stats["spec_waves"] += 1
         self.stats["occupied_slot_steps"] += len(active)
+        with self._phase("serve.emit"):
+            self._emit_wave(active, wave, t0)
+
+    def _emit_wave(self, active: list, wave: dict, t0: float):
+        """Book one speculative wave's accepted tokens, slot by slot."""
         now = 0.0
         if self.tm is not None:
             now = self.tm.clock()
             self.tm.spec_wave(
-                t0, n_active=len(active), k=k,
+                t0, n_active=len(active), k=self.spec_k,
                 accepted=sum(len(w) - 1 for w in wave.values()), now=now)
         for i in active:
             r = self.slots[i]
@@ -1119,7 +1177,6 @@ class ServeEngine:
                     break
         if self.tm is not None:
             self.tm.update_gauges(self._telemetry_gauges())
-        return True
 
     def _telemetry_gauges(self) -> dict:
         """Instantaneous cache-pressure / occupancy values, all host-side

@@ -81,10 +81,11 @@ def cache_insert_slots(pool, new, slots):
     Layout-generic: prefill encodes into the same codec layout as the pool,
     so every leaf pair (quantized values, scales, lengths) lines up.
     """
-    return jax.tree.map(
-        lambda dst, src: dst.at[:, slots].set(src.astype(dst.dtype),
-                                              mode="drop"),
-        pool, new)
+    with jax.named_scope("kv_pool"):
+        return jax.tree.map(
+            lambda dst, src: dst.at[:, slots].set(src.astype(dst.dtype),
+                                                  mode="drop"),
+            pool, new)
 
 
 def kv_pool_bytes(caches) -> int:
@@ -641,8 +642,9 @@ def paged_insert_prefill(pool, new, dest_pages):
             bs = dst.shape[2]
             count, g, t = src.shape[:3]
             src_p = src.reshape(count, g, t // bs, bs, *src.shape[3:])
-            seg[leaf_name] = dst.at[:, dest_pages].set(
-                src_p.astype(dst.dtype), mode="drop")
+            with jax.named_scope("kv_pool"):
+                seg[leaf_name] = dst.at[:, dest_pages].set(
+                    src_p.astype(dst.dtype), mode="drop")
         out[name] = seg
     return out
 

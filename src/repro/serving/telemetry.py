@@ -13,12 +13,27 @@ that ``ServeEngine(telemetry=...)`` threads through every tick:
                     phase spans, exported as Chrome trace-event JSON
                     (load the file in Perfetto / chrome://tracing).
 
+Engine phases — ``Telemetry.phase(name)``
+----------------------------------------
+The engine wraps each phase of a tick in ``phase``: ``serve.step`` (the
+whole tick) around ``serve.admit`` (scheduling, host arrays, prefill
+launch), ``serve.dispatch`` (a jitted launch), ``serve.sync`` (the one
+place the host waits on the device: sampled tokens pulled to the host)
+and ``serve.emit`` (token bookkeeping, stream callbacks, gauges). Each
+phase is a ``jax.profiler.TraceAnnotation``, so it lands on the
+profiler's host plane on the device trace's clock and an idle gap of the
+device can be charged to the innermost phase that covers it. Without a
+running profiler an annotation costs about a microsecond. Each tick that
+did work books ``serve_tick_host_seconds``: its wall time less its time
+in ``serve.sync``.
+
 The overhead contract — **zero extra device work**
 --------------------------------------------------
 Telemetry must never change what the engine launches. Everything in this
 module reads host clocks (``time.perf_counter``) and host integers the
-engine already holds; nothing here imports jax at module scope, touches a
-device array, or inserts a block/sync. Tick durations are honest anyway:
+engine already holds; nothing here imports jax at module scope (``phase``
+imports the profiler's annotation on first use), touches a device array,
+or inserts a block/sync. Tick durations are honest anyway:
 the engine's hot loop already synchronizes on every tick when it pulls
 sampled tokens to the host (``np.asarray`` on the jitted call's output),
 so the host wall-time between tick start and token consumption covers
@@ -34,6 +49,7 @@ start is an error, never a silent run without a device trace.
 
 from __future__ import annotations
 
+import contextlib
 import json
 import threading
 import time
@@ -336,15 +352,19 @@ class Telemetry:
             "time itself is TTFT's, not the queue's")
         self.prefill_s = r.histogram(
             "serve_prefill_wave_seconds", "one admission prefill wave")
-        self.prefill_slice_s = r.histogram(
-            "serve_prefill_slice_seconds",
-            "one interleaved prefill slice (chunked admission work "
-            "co-scheduled with decode ticks)")
         self.decode_s = r.histogram(
             "serve_decode_tick_seconds", "one batched decode tick")
         self.spec_s = r.histogram(
             "serve_spec_wave_seconds",
             "one fused draft+verify speculative wave")
+        self.tick_host_s = r.histogram(
+            "serve_tick_host_seconds",
+            "one engine tick that did work, less its time in serve.sync: "
+            "the host's share of the tick")
+        # phase seconds of the tick in flight, and the profiler's span
+        # class (imported on first use)
+        self._tick_phases: dict[str, float] = {}
+        self._annotation = None
         # lifecycle state, keyed by rid
         self._arrive: dict[int, float] = {}
         self._admit_t: dict[int, float] = {}
@@ -361,6 +381,31 @@ class Telemetry:
         reset honestly)."""
         self.registry.reset()
         self.tracer.clear()
+
+    # -- engine phases ------------------------------------------------------
+
+    @contextlib.contextmanager
+    def phase(self, name: str):
+        """One engine phase: a profiler host span named ``name`` on the
+        device trace's clock, and its perf_counter seconds added to the
+        tick's accounting (``tick_done``)."""
+        if self._annotation is None:
+            from jax.profiler import TraceAnnotation
+            self._annotation = TraceAnnotation
+        t0 = time.perf_counter()
+        with self._annotation(name):
+            yield
+        p = self._tick_phases
+        p[name] = p.get(name, 0.0) + time.perf_counter() - t0
+
+    def tick_done(self, worked: bool):
+        """Close the tick whose ``serve.step`` phase just ended: a tick that
+        did work books its wall time less its time in ``serve.sync``."""
+        p = self._tick_phases
+        if worked:
+            self.tick_host_s.observe(p.get("serve.step", 0.0)
+                                     - p.get("serve.sync", 0.0))
+        p.clear()
 
     # -- engine hooks -------------------------------------------------------
 
@@ -463,9 +508,10 @@ class Telemetry:
         """One interleaved prefill slice (a chunk of an admission group's
         prompt run alongside the decode batch). Sliced admissions book
         these instead of one prefill_wave span — the wave no longer exists
-        as a contiguous blocking interval."""
+        as a contiguous blocking interval. The span covers the slice's
+        dispatch only (nothing waits on its result); its device time is
+        the ``serve_prefill_slice`` module of a device trace."""
         now = self.clock() if now is None else now
-        self.prefill_slice_s.observe(now - t0)
         self.tracer.span("prefill_slice", t0, now,
                          args={"n_reqs": n_reqs, "tokens": tokens,
                                "bucket": bucket})

@@ -227,9 +227,9 @@ def test_engine_smoke_metrics_and_trace(model):
 
 
 def test_engine_smoke_interleave_slice_metrics(model):
-    """Interleaved prefill books prefill_slice spans (never a blocking
-    prefill_wave) and exports the slice histogram + job gauge — the
-    attribution surface the ITL audit hangs off."""
+    """Interleaved prefill books one prefill_slice span per slice (never a
+    blocking prefill_wave) and exports the job gauge — the attribution
+    surface the ITL audit hangs off."""
     cfg, api, params = model
     tm = Telemetry()
     eng = ServeEngine(api, params, max_batch=2, max_len=64,
@@ -238,14 +238,13 @@ def test_engine_smoke_interleave_slice_metrics(model):
     eng.add_request(np.arange(9) % cfg.vocab, max_new=3)
     eng.run()
     m = json.loads(tm.metrics_json())
-    assert m["histograms"]["serve_prefill_slice_seconds"]["count"] == \
-        eng.stats["prefill_slices"] > 0
     assert m["histograms"]["serve_prefill_wave_seconds"]["count"] == 0
     assert m["gauges"]["serve_prefill_jobs"] == 0.0       # drained
     evs = tm.chrome_trace()["traceEvents"]
-    eng_spans = {e["name"] for e in evs if e["ph"] == "X"
-                 and e["pid"] == T.ENGINE_PID}
-    assert "prefill_slice" in eng_spans
+    eng_spans = [e["name"] for e in evs if e["ph"] == "X"
+                 and e["pid"] == T.ENGINE_PID]
+    assert eng_spans.count("prefill_slice") == \
+        eng.stats["prefill_slices"] > 0
     assert "prefill_wave" not in eng_spans
 
 
@@ -269,6 +268,79 @@ def test_engine_smoke_spec_wave_metrics(model):
              if e["ph"] == "X" and e["name"] == "spec_wave"]
     assert len(waves) == eng.stats["spec_waves"]
     assert all(e["args"]["k"] == 2 for e in waves)
+
+
+# ---------------------------------------------------------------------------
+# engine phases on the profiler's clock, and named device scopes
+# ---------------------------------------------------------------------------
+
+PHASES = ("serve.admit", "serve.dispatch", "serve.sync", "serve.emit")
+
+
+def _drive_small(eng, cfg):
+    for p, mn in [(8, 5), (8, 7), (5, 3), (11, 4)]:
+        eng.add_request(np.arange(p) % cfg.vocab, max_new=mn)
+
+
+def test_engine_phases_nest_on_profiler_host_plane(model, tmp_path):
+    """Under jax.profiler every tick is a serve.step span on the host
+    plane, and the admit / dispatch / sync / emit phases lie inside one."""
+    from jax.profiler import ProfileData
+
+    cfg, api, params = model
+    eng = ServeEngine(api, params, max_batch=2, max_len=64,
+                      telemetry=Telemetry())
+    _drive_small(eng, cfg)
+    eng.step()                                 # compile outside the trace
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        eng.run()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = tmp_path.glob("**/*.xplane.pb")
+    spans = [(e.name, e.start_ns, e.start_ns + e.duration_ns)
+             for plane in ProfileData.from_file(str(path)).planes
+             if plane.name.startswith("/host:")
+             for line in plane.lines for e in line.events
+             if e.name.startswith("serve.")]
+    steps = [(s, e) for n, s, e in spans if n == "serve.step"]
+    assert len(steps) >= eng.stats["decode_steps"] - 1 > 0
+    children = [(n, s, e) for n, s, e in spans if n != "serve.step"]
+    assert {n for n, _, _ in children} == set(PHASES)
+    for n, s, e in children:
+        assert any(ps <= s and e <= pe for ps, pe in steps), n
+
+
+def test_tick_host_seconds_counts_ticks_that_did_work(model):
+    """serve_tick_host_seconds books one sample per tick that did work
+    (step() returned True), each the tick less its serve.sync time."""
+    cfg, api, params = model
+    tm = Telemetry()
+    eng = ServeEngine(api, params, max_batch=2, max_len=64, telemetry=tm)
+    _drive_small(eng, cfg)
+    worked = 0
+    while eng.step():
+        worked += 1
+    assert eng.step() is False                 # an idle tick books nothing
+    assert worked > 0
+    assert tm.tick_host_s.count == worked
+    assert all(x >= 0.0 for x in tm.tick_host_s.samples)
+    m = json.loads(tm.metrics_json())
+    assert m["histograms"]["serve_tick_host_seconds"]["count"] == worked
+
+
+def test_decode_hlo_carries_device_scopes(model):
+    """The decode step's op metadata names the KV pool, the attention and
+    the weights' sign-and-pack, and its module is named serve_decode."""
+    cfg, api, params = model
+    assert cfg.policy.binary_ffn
+    eng = ServeEngine(api, params, max_batch=2, max_len=64)
+    text = eng._decode.lower(eng.params, eng.caches,
+                             jax.numpy.asarray(eng.next_tok)).as_text(
+                                 debug_info=True)
+    assert "@jit_serve_decode" in text
+    for scope in ("kv_pool", "attn", "ffn_pack"):
+        assert f"/{scope}/" in text, scope
 
 
 # ---------------------------------------------------------------------------
