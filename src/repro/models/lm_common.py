@@ -149,7 +149,9 @@ def gqa_decode(p, x, cfg: ModelConfig, cache):
     (and for quantized codecs, the dequant-fused attend) is owned by the
     ``cfg.kv_cache`` codec — see serving/kvcache.py. A paged cache (block
     pool + per-slot block table, detected by its "table" leaf) inserts and
-    attends through the block table instead."""
+    attends through the block table instead; a stacked-pool view (its
+    "layer" leaf, from segments_decode) reads its layer of the whole
+    segment's pool in place and hands the new row back to the scan."""
     positions = cache["len"][:, None]  # (B, 1)
     q, k, v = gqa_qkv(p, x, cfg, positions)
     codec = kvc.get_codec(cfg.kv_cache)
@@ -550,6 +552,59 @@ def segments_apply(params, x, cfg: ModelConfig, *, positions):
     return x, total_aux
 
 
+def _decodes_in_place(cfg: ModelConfig, sig: BlockSig, cache) -> bool:
+    """A contiguous GQA pool in an ``in_place`` codec (bf16), updated by
+    per-slot dus (no sharded mesh): the scan reads the pool in place."""
+    return (sig.attn == "gqa" and "table" not in cache
+            and kvc.get_codec(cfg.kv_cache).in_place
+            and attn_lib.resolve_cache_update(cfg.cache_update) == "dus")
+
+
+def _segment_decode_in_place(stacked, x, cfg: ModelConfig, sig: BlockSig,
+                             cache, count: int):
+    """One segment's decode that leaves its stacked pool where it lies:
+    the layer scan reads each layer's K/V in place (kvcache's pool view,
+    the layer index in ``xs``) and collects each layer's new row as
+    ``ys``; one write per slot then puts every layer's row into the
+    donated pool. No layer is sliced out, relaid or written back. Each
+    layer reads its own ``len`` row (pre-insert) and all advance by one —
+    the values the per-layer scan produced."""
+    pool = {k: a for k, a in cache.items() if k != "len"}
+
+    def one(x, xs, sig=sig):
+        p, layer, lens = xs
+        y, view = block_decode(p, x, cfg, sig,
+                               {**pool, "len": lens, "layer": layer})
+        return y, view["new"]
+
+    xs = (stacked, jnp.arange(count, dtype=jnp.int32), cache["len"])
+    if cfg.scan_layers and count > 1:
+        x, rows = jax.lax.scan(one, x, xs)
+    else:
+        outs = []
+        for i in range(count):
+            x, r = one(x, jax.tree.map(lambda a: a[i], xs))
+            outs.append(r)
+        rows = jax.tree.map(lambda *a: jnp.stack(a), *outs)
+    with jax.named_scope("kv_pool"):
+        pool = kvc.write_pool_rows(pool, rows, cache["len"][0])
+    return x, {**pool, "len": cache["len"] + 1}
+
+
+def decode_read_block(cfg: ModelConfig, max_len: int) -> int:
+    """Rows per block that one decode tick's attention fetches from each
+    slot of a contiguous pool of ``max_len``: the in-place kernel's K/V
+    block where every segment takes it (a slot with n rows in the pool
+    reads ceil(n / block) blocks), else ``max_len`` (the whole slot)."""
+    from repro.kernels.decode_attention import block_rows
+    contiguous = {}                     # no "table" leaf
+    if (not _decodes_in_place(cfg, block_sig(cfg, 0), contiguous)
+            or attn_lib.resolve_attn_impl(cfg.attn_impl,
+                                          family="decode") != "pallas_flash"):
+        return max_len
+    return block_rows(max_len, cfg.n_kv_heads, cfg.kv_head_dim())
+
+
 def segments_decode(params, x, cfg: ModelConfig, caches):
     """caches: {'seg{i}': stacked_cache}; returns (x, new_caches)."""
     segs = build_segments(cfg)
@@ -557,6 +612,10 @@ def segments_decode(params, x, cfg: ModelConfig, caches):
     for si, (sig, start, count) in enumerate(segs):
         stacked = params[f"seg{si}"]
         cache = caches[f"seg{si}"]
+        if _decodes_in_place(cfg, sig, cache):
+            x, new_caches[f"seg{si}"] = _segment_decode_in_place(
+                stacked, x, cfg, sig, cache, count)
+            continue
 
         def one(x, pc, sig=sig):
             p, c = pc

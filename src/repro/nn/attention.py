@@ -25,9 +25,11 @@ Backends (``resolve_attn_impl``):
   "pallas_flash"   the Pallas flash kernel (TPU; interpret=True on CPU)
   "auto"           xla_ref on CPU (bit-compatible with the historical
                    path) and under a multi-device mesh, pallas_flash on
-                   one accelerator for prefill/cross;
-                   decode always resolves to xla_ref (a single-query
-                   attend is already O(T) with no score blowup)
+                   one accelerator; for decode that is the in-place
+                   stacked-pool kernel (kernels/decode_attention, through
+                   serving/kvcache's pool view), while decode_attention
+                   below, over a cache already sliced out of its pool,
+                   keeps xla_ref
 
 MLA decode stays on the matrix-absorbed path (scores against the
 compressed c_kv cache) — it never materializes expanded K/V at all, which
@@ -71,20 +73,17 @@ def resolve_kv_cache(impl: str = "auto") -> str:
 def resolve_attn_impl(impl: str = "auto", *, family: str = "prefill") -> str:
     """family in {prefill, decode, cross} -> concrete impl for this call.
 
-    Decode is one query against a cache: the scores are already O(T) and
-    the blockwise machinery buys nothing, so auto keeps the reference path.
-    On CPU auto also stays on xla_ref for prefill — it is bit-identical to
-    the pre-flash behaviour (tests and the serving parity suite depend on
-    that); the blockwise paths remain selectable explicitly everywhere.
-    Under a multi-device mesh it stays on xla_ref too: GSPMD partitions
-    that, not a Pallas kernel.
+    On CPU auto stays on xla_ref — it is bit-identical to the pre-flash
+    behaviour (tests and the serving parity suite depend on that); the
+    blockwise paths remain selectable explicitly everywhere. Under a
+    multi-device mesh it stays on xla_ref too: GSPMD partitions that, not
+    a Pallas kernel. On one accelerator it is pallas_flash; for decode
+    that names the kernel that reads the stacked pool in place.
     """
     if impl not in ATTN_IMPLS:
         raise ValueError(f"unknown attn impl {impl!r}; known: {ATTN_IMPLS}")
     if impl != "auto":
         return impl
-    if family == "decode":
-        return "xla_ref"
     from repro.distributed.sharding import sharded_mesh_active
     if jax.default_backend() == "cpu" or sharded_mesh_active():
         return "xla_ref"
@@ -204,8 +203,11 @@ def decode_attention(q, k, v, *, kv_len, scale: float | None = None,
                      impl: str = "auto"):
     """Single-query (S small) attend over a preallocated cache; kv_len is
     the valid cache length per sequence (slot pools decode the full
-    preallocated T every tick and mask the tail)."""
-    impl = resolve_attn_impl(impl, family="decode")
+    preallocated T every tick and mask the tail). The cache is already
+    sliced out of its pool, so auto keeps the reference here; the in-place
+    pool kernel is serving/kvcache's stacked-view attend."""
+    impl = resolve_attn_impl("xla_ref" if impl == "auto" else impl,
+                             family="decode")
     if impl == "xla_ref":
         return dot_attention(q, k, v, causal=False, kv_len=kv_len,
                              scale=scale)

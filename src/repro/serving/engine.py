@@ -314,6 +314,13 @@ class ServeEngine:
         else:
             self.pool_len = max_len
             init_pool = partial(api.init_cache, max_batch, max_len)
+        # rows per block a decode tick's attention fetches from each slot
+        # (the serve_decode_kv_rows_* counters): the in-place kernel's
+        # block, or the whole slot on every other path
+        from repro.models.lm_common import decode_read_block
+        self._kv_read_block = (
+            decode_read_block(api.cfg, self.pool_len)
+            if not self.paged and mesh is None else self.pool_len)
         if mesh is None:
             self.caches = init_pool()
         else:
@@ -1060,7 +1067,15 @@ class ServeEngine:
             now = 0.0
             if self.tm is not None:
                 now = self.tm.clock()
+                # each slot's rows in the pool before this tick's insert
+                # (its new row is attended beside the pool, not read)
+                blk, cap = self._kv_read_block, self.pool_len
+                rows = [len(self.slots[i].prompt) + len(self.slots[i].out)
+                        - 1 for i in active]
                 self.tm.decode_tick(t0, n_active=len(active), now=now)
+                self.tm.decode_kv_rows(
+                    read=sum(min(-(-n // blk) * blk, cap) for n in rows),
+                    pool=len(active) * cap)
             for i in active:
                 r = self.slots[i]
                 if self.paged and self.prefix_on:

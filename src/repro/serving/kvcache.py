@@ -30,6 +30,16 @@ bounded by the block size. Quantize/dequantize lower through
 MLA's compressed ``(c_kv, k_rope)`` cache is already the memory
 optimization for that attention family and stays bf16; the ``kv_cache``
 knob applies to GQA-family K/V pools (dense/MoE transformer blocks).
+
+Stacked-pool views. The bf16 codec (``in_place``) also decodes against a
+*view* of a segment's whole stacked pool: ``{"k", "v": (L, B, T, Hkv, D),
+"len": (B,), "layer": int32 scalar}``. Its insert leaves the pool as it
+is and puts the encoded row in the view (``"new"``); its attend reads
+layer ``layer`` where it lies, with the new row beside it
+(``kernels/decode_attention``). The decode scan collects every layer's
+row and ``write_pool_rows`` puts them into the donated pool after it
+(``models/lm_common.segments_decode``): nothing of the pool is sliced,
+copied or written back per layer.
 """
 
 from __future__ import annotations
@@ -276,6 +286,26 @@ def _write_timestep(cache, new_leaves, *, method):
     return out
 
 
+def write_pool_rows(pool, new_leaves, idx):
+    """Write one decode tick's rows into a segment's stacked pool, after
+    its layer scan: ``new_leaves`` holds (L, B, 1, ...) per leaf, written
+    at ``[:, b, idx[b]]`` — every layer's ``len`` row is the same (prefill
+    scatter, set_cache_lengths and decode keep them so). One
+    dynamic_update_slice per slot, which XLA applies to the donated pool
+    in place whatever its memory layout (a scatter would pin the pool
+    row-major and copy it); start indices clamp as ``_write_timestep``'s
+    per-slot dus does."""
+    out = dict(pool)
+    for name, new in new_leaves.items():
+        buf = pool[name]
+        new = new.astype(buf.dtype)
+        for b in range(buf.shape[1]):
+            start = (0, b, idx[b]) + (0,) * (buf.ndim - 3)
+            buf = jax.lax.dynamic_update_slice(buf, new[:, b:b + 1], start)
+        out[name] = buf
+    return out
+
+
 # ---------------------------------------------------------------------------
 # dequant-fused decode: blockwise online softmax over the encoded cache
 # ---------------------------------------------------------------------------
@@ -356,6 +386,8 @@ class CacheCodec:
     scatter / scan stacking / donation never see the codec."""
 
     name: str = ""
+    # decodes against a stacked-pool view (module doc) in place
+    in_place: bool = False
 
     # layout-generic by construction (time-axis leaves + a ``len`` leaf are
     # the layout contract, so one tree scatter / len rewrite serves every
@@ -424,6 +456,7 @@ class Bf16Codec(CacheCodec):
     parity test (and ``kv_cache="auto"``) is unchanged bit for bit."""
 
     name = "bf16"
+    in_place = True
 
     def init(self, batch, max_len, n_kv, head_dim, dtype=jnp.bfloat16):
         return attn_lib.init_kv_cache(batch, max_len, n_kv, head_dim, dtype)
@@ -432,6 +465,9 @@ class Bf16Codec(CacheCodec):
         return {"k": k, "v": v}
 
     def insert_timestep(self, cache, k_new, v_new, *, method="auto"):
+        if "layer" in cache:
+            return {**cache, "new": self.encode(k_new, v_new),
+                    "len": cache["len"] + 1}
         # delegate to the historical update (bit-compatible by construction)
         return attn_lib.cache_update_decode(cache, k_new, v_new,
                                             method=method)
@@ -446,6 +482,8 @@ class Bf16Codec(CacheCodec):
             # blockwise attend (bf16 passes through dequant_block)
             return _fused_quant_decode(q, cache, self, scale=scale,
                                        q_lens=q_lens)
+        if "layer" in cache:
+            return _pool_decode_attention(q, cache, scale=scale, impl=impl)
         return attn_lib.decode_attention(q, cache["k"], cache["v"],
                                          kv_len=cache["len"], scale=scale,
                                          impl=impl)
@@ -460,6 +498,28 @@ class Bf16Codec(CacheCodec):
 
     def bytes_per_token(self, n_kv, head_dim):
         return 2 * n_kv * head_dim * 2
+
+
+def _pool_decode_attention(q, view, *, scale=None, impl="auto"):
+    """Single-query attend over layer ``view["layer"]`` of a stacked pool
+    view plus its new row, as if the row sat at ``len - 1`` (``len``
+    counts it; a full slot's row takes the last place, as the clamped
+    insert does). ``auto`` picks the in-place kernel on one accelerator;
+    the XLA paths insert the row into the layer's slice and attend."""
+    impl = attn_lib.resolve_attn_impl(impl, family="decode")
+    layer, new = view["layer"], view["new"]
+    if impl == "pallas_flash":
+        from repro.kernels.decode_attention import decode_attention_pallas
+        t = view["k"].shape[2]
+        return decode_attention_pallas(
+            q[:, 0], new["k"][:, 0], new["v"][:, 0], view["k"], view["v"],
+            layer, jnp.minimum(view["len"] - 1, t - 1), scale=scale)[:, None]
+    cache = attn_lib.cache_update_decode(
+        {"k": view["k"][layer], "v": view["v"][layer],
+         "len": view["len"] - 1}, new["k"], new["v"], method="dus")
+    return attn_lib.decode_attention(q, cache["k"], cache["v"],
+                                     kv_len=cache["len"], scale=scale,
+                                     impl=impl)
 
 
 class Int8Codec(CacheCodec):

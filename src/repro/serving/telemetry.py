@@ -354,6 +354,14 @@ class Telemetry:
             "serve_prefill_wave_seconds", "one admission prefill wave")
         self.decode_s = r.histogram(
             "serve_decode_tick_seconds", "one batched decode tick")
+        self.kv_rows_read = r.counter(
+            "serve_decode_kv_rows_read",
+            "KV pool rows decode attention fetched: each active slot's "
+            "valid blocks, summed over decode ticks")
+        self.kv_rows_pool = r.counter(
+            "serve_decode_kv_rows_pool",
+            "KV pool rows decode attention could fetch: active slots x "
+            "max_len, summed over decode ticks")
         self.spec_s = r.histogram(
             "serve_spec_wave_seconds",
             "one fused draft+verify speculative wave")
@@ -522,6 +530,13 @@ class Telemetry:
         self.decode_s.observe(now - t0)
         self.tracer.span("decode_tick", t0, now,
                          args={"n_active": n_active})
+
+    def decode_kv_rows(self, *, read: int, pool: int):
+        """The pool rows one decode tick's attention fetched and could have
+        fetched (their ratio is the engaged share), from the host's
+        lengths, not the device."""
+        self.kv_rows_read.inc(read)
+        self.kv_rows_pool.inc(pool)
 
     def spec_wave(self, t0: float, *, n_active: int, k: int, accepted: int,
                   now: float | None = None):

@@ -212,10 +212,11 @@ def test_resolve_attn_impl():
     # explicit impls pass through untouched
     for impl in ("xla_ref", "xla_blockwise", "pallas_flash"):
         assert A.resolve_attn_impl(impl, family="prefill") == impl
-    # auto: decode stays on the reference; prefill picks per backend
-    assert A.resolve_attn_impl("auto", family="decode") == "xla_ref"
+    # auto picks per backend, for decode (the in-place pool kernel) as
+    # for prefill
     expected = ("xla_ref" if jax.default_backend() == "cpu"
                 else "pallas_flash")
+    assert A.resolve_attn_impl("auto", family="decode") == expected
     assert A.resolve_attn_impl("auto", family="prefill") == expected
     with pytest.raises(ValueError):
         A.resolve_attn_impl("triton_flash")

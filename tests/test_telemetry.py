@@ -503,3 +503,33 @@ def test_xla_profiler_warns_once_and_keeps_serving(monkeypatch):
     for _ in range(2):
         with pytest.raises(RuntimeError, match="no profiler"):
             T.start_xla_profiler("/tmp/nowhere")
+
+
+@pytest.mark.parametrize("attn_impl,read", [("auto", 1536),
+                                            ("pallas_flash", 1024)])
+def test_decode_kv_rows_counters(model, monkeypatch, attn_impl, read):
+    """serve_decode_kv_rows_read / _pool from known lengths. Prompts of
+    127 and 100 tokens in one wave, 5 and 3 tokens out: 6 slot-ticks of a
+    256-row pool (1536 rows). The in-place kernel, at 128-row blocks,
+    fetches ceil(rows / 128) blocks of the rows already in the pool (the
+    tick's new row is attended beside it): 127, 128, 129, 130 rows (1, 1,
+    2, 2 blocks) and 100, 101 (1, 1) are 1024 rows; the CPU's xla_ref
+    reads every row."""
+    from repro.kernels import decode_attention as da
+    from repro.models.lm_common import decode_read_block
+    monkeypatch.setattr(da, "_BLOCK_BYTES", 2**14)
+    cfg, api, params = model
+    assert decode_read_block(cfg.replace(attn_impl=attn_impl), 256) == (
+        128 if attn_impl == "pallas_flash" else 256)
+    tm = Telemetry()
+    eng = ServeEngine(api, params, max_batch=2, max_len=256,
+                      attn_impl=attn_impl, telemetry=tm)
+    eng.add_request(np.arange(127) % cfg.vocab, max_new=5)
+    eng.add_request(np.arange(100) % cfg.vocab, max_new=3)
+    eng.run()
+    c = json.loads(tm.metrics_json())["counters"]
+    assert eng.stats["occupied_slot_steps"] == 6
+    assert c["serve_decode_kv_rows_pool"] == 6 * 256
+    assert c["serve_decode_kv_rows_read"] == read
+    assert c["serve_decode_kv_rows_read"] / c["serve_decode_kv_rows_pool"] \
+        == pytest.approx(read / 1536)
